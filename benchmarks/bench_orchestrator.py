@@ -32,6 +32,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).parent.parent / "src"))
 
 import jax
 
+from repro.core.analytical import TPU_V5E
 from repro.models import transformer as T
 from repro.models.config import Family, ModelConfig
 from repro.serving.api import Server
@@ -79,6 +80,7 @@ def main() -> dict:
             # backend-agnostic drive: every mode goes through the Server
             # front door (the same surface the sim benches use)
             server = Server(Orchestrator(CFG, params, OrchestratorConfig(
+                hw=TPU_V5E,
                 n_prefill=3, n_decode=3, engine=ecfg, migration=False,
                 chunk_tokens=16, slo=slo, **kw)))
             s = server.run(generate(wl))

@@ -84,6 +84,7 @@ def _run_arm(mode: str, n_requests: int) -> dict:
     keys, n_full = _hot_prefix_keys(reqs)
     params = T.init(CFG, __import__("jax").random.PRNGKey(0))
     orch = Orchestrator(CFG, params, OrchestratorConfig(
+        hw=A.TPU_V5E,
         n_prefill=1, n_decode=1, migration=False, engine=ECFG,
         global_store=(mode != "recompute"),
         prefix_sharing=(mode == "shared")))

@@ -18,6 +18,8 @@ import pathlib
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import (bench_attention, bench_autoscale, bench_chunked_prefill,
                bench_decode_attention, bench_layer_span, bench_migration,
                bench_orchestrator, bench_paged_handoff, bench_pipeline,
@@ -53,6 +55,7 @@ def main() -> None:
     args = ap.parse_args()
     if args.smoke:
         os.environ["BENCH_SMOKE"] = "1"
+    enable_compile_cache()
     out_dir = pathlib.Path(args.out)
     out_dir.mkdir(parents=True, exist_ok=True)
     names = [args.only] if args.only else list(ALL)

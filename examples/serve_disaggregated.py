@@ -19,8 +19,12 @@ token-for-token against a single-engine reference rollout: disaggregation,
 chunked prefill, migration and *streaming consumption* change when and
 where work runs, never what is computed.
 
-    PYTHONPATH=src python examples/serve_disaggregated.py
-    PYTHONPATH=src python examples/serve_disaggregated.py --speculation ngram
+    PYTHONPATH=src python examples/serve_disaggregated.py --hw tpu_v5e
+    PYTHONPATH=src python examples/serve_disaggregated.py --hw tpu_v5e \
+        --speculation ngram
+
+``--hw`` names the part the virtual clock bills; left out, it is the
+device's own part (``analytical.device_profile``), which a CPU has not.
 """
 import argparse
 import dataclasses
@@ -49,6 +53,9 @@ def main():
                     help="speculative decoding on decode units; the exact "
                          "verify keeps the streamed outputs token-identical "
                          "to the plain reference either way")
+    ap.add_argument("--hw", default=None, choices=sorted(A.PROFILES),
+                    help="the part the virtual clock bills (default: the "
+                         "device's own; a CPU run must name one)")
     args = ap.parse_args()
 
     cfg = configs.get("gemma-7b").smoke()
@@ -60,7 +67,8 @@ def main():
     # 'draft' here is a self-draft (the target's own params) — a degenerate
     # but deterministic draft model that demonstrates the accept-all path
     draft = (cfg, params) if args.speculation == "draft" else None
-    hw = A.TPU_V5E
+    hw = (A.PROFILES[args.hw] if args.hw
+          else A.device_profile(jax.devices()[0]))
     # saturating Poisson arrivals + SLO targets derived from the model's
     # own analytical costs, so the demo is meaningful at any model size
     t_pref = A.prefill_time(cfg, 48, hw)

@@ -52,6 +52,24 @@ TPU_V4 = HardwareProfile("tpu_v4", 275e12, 1228e9, 32 << 30, 50e9, 16e9)
 PROFILES: Dict[str, HardwareProfile] = {
     p.name: p for p in (TPU_V5E, TPU_V5P, TPU_V4, A100_80G)}
 
+# The part behind each ``device_kind`` string JAX reports.
+DEVICE_KINDS: Dict[str, HardwareProfile] = {
+    "TPU v5 lite": TPU_V5E, "TPU v5": TPU_V5P, "TPU v4": TPU_V4}
+
+
+def device_profile(device) -> HardwareProfile:
+    """The profile of a JAX device, looked up by its ``device_kind``.  A
+    kind not in ``DEVICE_KINDS`` (the CPU among them) raises: a live
+    fleet on an unknown part must not be billed as some other part.
+    Runs that model a fleet they do not run on name the profile."""
+    try:
+        return DEVICE_KINDS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware profile for device kind {device.device_kind!r} "
+            f"(known: {sorted(DEVICE_KINDS)}); pass the modelled part "
+            f"explicitly, e.g. analytical.PROFILES['tpu_v5e']") from None
+
 
 @functools.lru_cache(maxsize=None)
 def model_consts(cfg: ModelConfig) -> Tuple[float, int, float]:

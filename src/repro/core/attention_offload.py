@@ -30,26 +30,12 @@ Three implementations, all bit-agreeing up to float assoc.:
 from __future__ import annotations
 
 import functools
-import inspect
 import math
 from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-
-# jax API drift: shard_map moved out of jax.experimental, and its
-# replication-check kwarg was renamed check_rep -> check_vma — two
-# independent changes, so detect the kwarg by signature, not location
-try:
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-_SHARD_MAP_KW = (
-    {"check_vma": False}
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else {"check_rep": False})
-
 
 # ---------------------------------------------------------------------------
 # Building blocks
@@ -168,12 +154,12 @@ def sharded_decode_attention(mesh, q: jax.Array, k: jax.Array, v: jax.Array,
         mg = jax.lax.all_gather(m, seq_axis)
         return combine_partials(list(og), list(lg), list(mg)).astype(qb.dtype)
 
-    return _shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(None, seq_axis, None, None), P(None, seq_axis, None, None),
                   P(None, seq_axis)),
         out_specs=P(),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )(q, k, v, kv_valid)
 
 

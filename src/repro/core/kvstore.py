@@ -215,17 +215,16 @@ class GlobalKVStore:
     @staticmethod
     def _layer_schedule(e: _Entry, payload: Any):
         """Memoized ordered per-layer byte schedule of an entry's payload;
-        () for opaque (non request-state) payloads.  ``payload`` is passed
-        in because page-resident entries materialize theirs per fetch (the
-        schedule shape is stable, so memoizing on the entry stays valid)."""
+        () for opaque (non request-state) payloads.  A request-state
+        payload the schedule cannot read raises: billing it as opaque would
+        hide a corrupt entry.  ``payload`` is passed in because
+        page-resident entries materialize theirs per fetch (the schedule
+        shape is stable, so memoizing on the entry stays valid)."""
         if e.sched is None:
             e.sched = ()
             if isinstance(payload, dict) and "groups" in payload:
                 from ..models.kvcache import layer_transfer_schedule
-                try:
-                    e.sched = tuple(layer_transfer_schedule(payload))
-                except Exception:
-                    pass
+                e.sched = tuple(layer_transfer_schedule(payload))
         return e.sched
 
     # -- insert ----------------------------------------------------------

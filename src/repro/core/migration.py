@@ -45,7 +45,7 @@ class MigrationAction:
     predicted_cost: float      # seconds
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(frozen=True)
 class ControllerConfig:
     delta_up: float = 0.35         # hysteresis: start migrating above this gap
     delta_down: float = 0.15       # ... stop once gap is below this

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .split_kv_decode import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -81,8 +79,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale: float, bq: int,
         if partials:
             # unnormalized (o, l, m) — combine_partials owns the division
             o_ref[0, 0] = acc_scr[...].astype(o_ref.dtype)
-            l_ref[0, 0] = l_scr[...]
-            m_ref[0, 0] = m_scr[...]
+            l_ref[0, 0, 0] = l_scr[...]
+            m_ref[0, 0, 0] = m_scr[...]
         else:
             l = jnp.maximum(l_scr[...], 1e-30)
             o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
@@ -127,9 +125,11 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
     out_shape = [jax.ShapeDtypeStruct(
         (b, h, s, d), jnp.float32 if return_partials else q.dtype)]
     if return_partials:
-        lspec = pl.BlockSpec((1, 1, bq), lambda b_, h_, i, j: (b_, h_, i))
+        # (b, h, 1, s): a (1, bq) block keeps Mosaic's (8, 128) tiling rule
+        lspec = pl.BlockSpec((1, 1, 1, bq),
+                             lambda b_, h_, i, j: (b_, h_, 0, i))
         out_specs += [lspec, lspec]
-        out_shape += [jax.ShapeDtypeStruct((b, h, s), jnp.float32)] * 2
+        out_shape += [jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32)] * 2
     out = pl.pallas_call(
         kernel,
         grid=(b, h, n_q, n_k),
@@ -147,122 +147,13 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=None if interpret else tpu_compiler_params(
-            ("parallel", "parallel", "parallel", "arbitrary")),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
     )(qt, kt, vt)
     if return_partials:
         o, ll, mm = out
-        return (o.transpose(0, 2, 1, 3), ll.transpose(0, 2, 1),
-                mm.transpose(0, 2, 1))
+        return (o.transpose(0, 2, 1, 3), ll[:, :, 0].transpose(0, 2, 1),
+                mm[:, :, 0].transpose(0, 2, 1))
     return out.transpose(0, 2, 1, 3)
-
-
-def _paged_prefix_kernel(tbl_ref, posq_ref, q_ref, k_ref, v_ref, pos_ref,
-                         o_ref, l_ref, m_ref, *, scale: float,
-                         kv_heads: int, group: int, window: Optional[int],
-                         soft_cap: Optional[float]):
-    """One (b, page-slot) grid step: every query in the chunk attends over
-    ONE physical prefix page, block-table-steered by the index_map (the
-    page axis is the partition axis of the split softmax)."""
-    b_ = pl.program_id(0)
-    j = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)                      # (S, H, D)
-    k = k_ref[0].astype(jnp.float32)                      # (bs, KV, D)
-    v = v_ref[0].astype(jnp.float32)
-    pos = pos_ref[0]                                      # (bs,)
-    s_len, h, d = q.shape
-    bs = k.shape[0]
-    pos_q = posq_ref[b_]                                  # (S,) absolute
-    page_ok = (tbl_ref[b_, j] >= 0) & (pos >= 0)          # (bs,)
-    causal = pos[None, :] <= pos_q[:, None]               # (S, bs)
-    if window is not None:
-        causal &= pos[None, :] > pos_q[:, None] - window
-    mask = page_ok[None, :] & causal                      # (S, bs)
-
-    qg = q.reshape(s_len, kv_heads, group, d) \
-          .transpose(1, 0, 2, 3).reshape(kv_heads, s_len * group, d)
-    sc = jax.lax.dot_general(
-        qg, k.transpose(1, 2, 0),                         # (KV,SG,D)x(KV,D,bs)
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) * scale       # (KV, S*G, bs)
-    if soft_cap is not None:
-        sc = jnp.tanh(sc / soft_cap) * soft_cap
-    mg = jnp.broadcast_to(mask[:, None, :], (s_len, group, bs)) \
-            .reshape(s_len * group, bs)
-    sc = jnp.where(mg[None], sc, NEG_INF)
-    m = jnp.max(sc, axis=-1)                              # (KV, S*G)
-    p = jnp.exp(sc - m[..., None])
-    p = jnp.where(mg[None], p, 0.0)
-    l = jnp.sum(p, axis=-1)
-    o = jax.lax.dot_general(
-        p, v.transpose(1, 0, 2),                          # (KV,SG,bs)x(KV,bs,D)
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)               # (KV, S*G, D)
-    o_ref[0, 0] = o.reshape(kv_heads, s_len, group, d) \
-                   .transpose(1, 0, 2, 3).reshape(s_len, h, d)
-    l_ref[0, 0] = l.reshape(kv_heads, s_len, group) \
-                   .transpose(1, 0, 2).reshape(s_len, h)
-    m_ref[0, 0] = m.reshape(kv_heads, s_len, group) \
-                   .transpose(1, 0, 2).reshape(s_len, h)
-
-
-def paged_prefix_partials(q: jax.Array, k_pages: jax.Array,
-                          v_pages: jax.Array, pos_pages: jax.Array,
-                          block_tables: jax.Array, positions: jax.Array, *,
-                          window: Optional[int] = None,
-                          scale: Optional[float] = None,
-                          soft_cap: Optional[float] = None,
-                          interpret: bool = False):
-    """Chunked-prefill prefix attention read straight out of the page pool.
-
-    q: (B, S, H, D) resume-chunk queries; k/v_pages: (P, bs, KV, D) pools;
-    pos_pages: (P, bs); block_tables: (B, nb) (-1 = unassigned, page 0 is
-    reserved scratch); positions: (B, S) absolute query positions.  The
-    block table and positions ride as scalar-prefetch operands, so each
-    grid step's index_map resolves the row's j-th physical page — the
-    prefix is never gathered into a dense view.  Returns per-page partials
-    o (B, nb, S, H, D) f32 and l/m (B, nb, S, H) f32."""
-    b, s, h, d = q.shape
-    bs, kv = k_pages.shape[1], k_pages.shape[2]
-    nb = block_tables.shape[1]
-    group = h // kv
-    if scale is None:
-        scale = 1.0 / math.sqrt(d)
-    kernel = functools.partial(
-        _paged_prefix_kernel, scale=scale, kv_heads=kv, group=group,
-        window=window, soft_cap=soft_cap)
-
-    def page(idx_fn):
-        return lambda b_, j, tbl, pq: idx_fn(jnp.maximum(tbl[b_, j], 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nb),
-        in_specs=[
-            pl.BlockSpec((1, s, h, d), lambda b_, j, tbl, pq: (b_, 0, 0, 0)),
-            pl.BlockSpec((1, bs, kv, d), page(lambda p_: (p_, 0, 0, 0))),
-            pl.BlockSpec((1, bs, kv, d), page(lambda p_: (p_, 0, 0, 0))),
-            pl.BlockSpec((1, bs), page(lambda p_: (p_, 0))),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, s, h, d),
-                         lambda b_, j, tbl, pq: (b_, j, 0, 0, 0)),
-            pl.BlockSpec((1, 1, s, h), lambda b_, j, tbl, pq: (b_, j, 0, 0)),
-            pl.BlockSpec((1, 1, s, h), lambda b_, j, tbl, pq: (b_, j, 0, 0)),
-        ],
-    )
-    o, l, m = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, nb, s, h, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, nb, s, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, nb, s, h), jnp.float32),
-        ],
-        compiler_params=None if interpret else tpu_compiler_params(
-            ("parallel", "parallel")),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), positions.astype(jnp.int32),
-      q, k_pages, v_pages, pos_pages)
-    return o, l, m

@@ -1,8 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-Handles padding to block multiples, the CPU-interpret fallback (this
-container validates kernels with interpret=True; on TPU the same call sites
-compile the real kernels), and the partial-combine epilogue for decode.
+Handles padding to block multiples, the backend choice (compiled kernels
+on a TPU, Pallas interpret mode on the CPU, where the tests validate the
+same kernel bodies; any other backend is an error), and the
+partial-combine epilogue for decode.
 """
 from __future__ import annotations
 
@@ -14,13 +15,19 @@ import jax
 import jax.numpy as jnp
 
 from ..core.attention_offload import combine_partials
-from .flash_prefill import flash_prefill, paged_prefix_partials
-from .split_kv_decode import (paged_decode_partials, paged_verify_partials,
+from .flash_prefill import flash_prefill
+from .split_kv_decode import (paged_decode_partials, paged_partials,
                               split_kv_decode_partials)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Interpret mode on the CPU, compiled kernels on a TPU.  Any other
+    backend raises: a run that landed somewhere unexpected must not pass
+    for a kernel run."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"no Pallas kernel path for backend {backend!r}")
+    return backend == "cpu"
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int, value=0.0) -> jax.Array:
@@ -43,7 +50,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     q: (B, S, H, D); k, v: (B, S, KV, D).  Returns (B, S, H, D)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret()
     b, s, h, d = q.shape
     pow2 = 1 << max((s - 1).bit_length(), 3)
     bq = min(block_q, pow2)
@@ -60,7 +67,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      valid: jax.Array, *,
-                     block_k: int = 512,
+                     block_k: int = 256,
                      interpret: Optional[bool] = None) -> jax.Array:
     """Single-token decode attention over a (ring or linear) KV cache.
 
@@ -68,7 +75,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     Kernel emits per-block partials; the exact softmax is reconstructed via
     combine_partials (Eq. 8–10)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret()
     bk = min(block_k, k.shape[1])
     kp = _pad_to(k, 1, bk)
     vp = _pad_to(v, 1, bk)
@@ -108,7 +115,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     block_tables: (B, nb) (-1 = unassigned); pos_q: (B,).
     Returns (B, H, D) in q's dtype."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret()
     o, l, m = paged_decode_partials(
         q, k_pages, v_pages, pos_pages, block_tables, pos_q,
         window=window, scale=scale, soft_cap=soft_cap,
@@ -144,8 +151,8 @@ def paged_verify_attention(q: jax.Array, k_pages: jax.Array,
     q: (B, S, H, D); k/v_pages: (P, bs, KV, D); pos_pages: (P, bs);
     block_tables: (B, nb); pos_q: (B, S).  Returns (B, S, H, D)."""
     if interpret is None:
-        interpret = not _on_tpu()
-    o, l, m = paged_verify_partials(
+        interpret = _interpret()
+    o, l, m = paged_partials(
         q, k_pages, v_pages, pos_pages, block_tables, pos_q,
         window=window, scale=scale, soft_cap=soft_cap,
         k_scale_pages=k_scale_pages, v_scale_pages=v_scale_pages,
@@ -180,10 +187,10 @@ def paged_prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     k/v_pages: (P, bs, KV, D); pos_pages: (P, bs); block_tables: (B, nb);
     positions: (B, S) absolute query positions.  Returns (B, S, H, D)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret()
     b, s, h, d = q.shape
     # prefix partition: one partial per physical page
-    po, plv, pm = paged_prefix_partials(
+    po, plv, pm = paged_partials(
         q, k_pages, v_pages, pos_pages, block_tables, positions,
         window=window, scale=scale, soft_cap=soft_cap, interpret=interpret)
     # suffix partition: causal flash over the chunk itself (both axes are
@@ -208,11 +215,11 @@ def paged_prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def decode_partials(q: jax.Array, k: jax.Array, v: jax.Array,
-                    valid: jax.Array, *, block_k: int = 512,
+                    valid: jax.Array, *, block_k: int = 256,
                     interpret: Optional[bool] = None):
     """Raw partials — what attention-level migration ships across devices."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret()
     bk = min(block_k, k.shape[1])
     kp = _pad_to(k, 1, bk)
     vp = _pad_to(v, 1, bk)

@@ -3,9 +3,14 @@
     # analytical cluster simulation (no model compute, paper-scale configs)
     PYTHONPATH=src python -m repro.launch.serve --backend sim --smoke
 
-    # live disaggregated fleet over the real JAX model (virtual clock)
+    # live disaggregated fleet over the real JAX model (virtual clock);
+    # on the CPU name the part the clock models
     PYTHONPATH=src python -m repro.launch.serve --backend live --smoke \\
-        --arch gemma-7b --requests 12
+        --arch gemma-7b --requests 12 --hw tpu_v5e
+
+    # full published width, bf16 weights, on one TPU chip (every fleet
+    # member shares the device; the clock bills the device's own part)
+    PYTHONPATH=src python -m repro.launch.serve --backend live
 
 The pre-orchestrator wall-clock loop that used to live here (one
 prefill/decode pair, no routing, no migration) is retired: both backends
@@ -22,14 +27,21 @@ from __future__ import annotations
 import argparse
 
 from .. import configs
+from ..core import analytical as A
 from ..serving.api import Server
 from ..serving.workload import ClosedLoopClients, WorkloadConfig, generate
+from .compile_cache import enable_compile_cache
+
+
+# chunked prefill: one row computes at most this many prompt tokens per
+# wave, which bounds a wave's temporaries on one chip
+CHUNK_TOKENS = 256
 
 
 def _build_live(args):
     import jax
+    import jax.numpy as jnp
 
-    from ..core import analytical as A
     from ..models import transformer as T
     from ..serving.engine import EngineConfig
     from ..serving.orchestrator import Orchestrator, OrchestratorConfig
@@ -37,8 +49,12 @@ def _build_live(args):
     cfg = configs.get(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
-    print(f"live backend: arch={cfg.name} params={cfg.param_count():,}")
-    params = T.init(cfg, jax.random.PRNGKey(0))
+    # full-width weights serve in bf16 (the KV pools follow the embedding
+    # dtype); smoke shrinks keep f32
+    dtype = jnp.float32 if args.smoke else jnp.bfloat16
+    print(f"live backend: arch={cfg.name} params={cfg.param_count():,} "
+          f"dtype={jnp.dtype(dtype).name}")
+    params = T.init(cfg, jax.random.PRNGKey(0), dtype)
     ecfg = EngineConfig(max_len=args.max_len, max_batch=args.max_batch,
                         block_size=16, speculation=args.speculation)
     draft = None
@@ -51,7 +67,8 @@ def _build_live(args):
         draft = (dcfg, params if dcfg == cfg
                  else T.init(dcfg, jax.random.PRNGKey(1)))
         print(f"draft model: {dcfg.name} params={dcfg.param_count():,}")
-    hw = A.TPU_V5E
+    hw = (A.PROFILES[args.hw] if args.hw
+          else A.device_profile(jax.devices()[0]))
     # --rps is in arrivals per decode-iteration time, so the offered load
     # is meaningful at any model scale on the virtual clock
     t_iter = A.decode_iter_time(cfg, args.max_len, hw, batch=args.max_batch)
@@ -63,7 +80,7 @@ def _build_live(args):
                         prompt_len_hi=min(64, args.max_len // 2))
     orch = Orchestrator(cfg, params, OrchestratorConfig(
         n_prefill=args.prefill, n_decode=args.decode, engine=ecfg, hw=hw,
-        chunk_tokens=32), draft=draft)
+        chunk_tokens=CHUNK_TOKENS), draft=draft)
     return orch, wl, 1e6  # report in virtual microseconds
 
 
@@ -92,7 +109,7 @@ def _build_sim(args):
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--backend", choices=("live", "sim"), default="live")
-    ap.add_argument("--arch", default="llama-13b")
+    ap.add_argument("--arch", default="granite-moe-3b-a800m")
     ap.add_argument("--smoke", action="store_true",
                     help="CPU-sized model (live) / shrunken workload (sim)")
     ap.add_argument("--requests", type=int, default=24)
@@ -100,11 +117,17 @@ def main() -> None:
                     help="live: arrivals per decode-iteration time; "
                          "sim: arrivals/s")
     ap.add_argument("--max-new", type=int, default=16)
-    ap.add_argument("--max-len", type=int, default=256)
+    # live defaults: a fleet whose weights, pools and step temporaries fit
+    # one 16 GB chip at full width
+    ap.add_argument("--max-len", type=int, default=1024)
     ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--prefix-share", type=float, default=0.6)
-    ap.add_argument("--prefill", type=int, default=2)
-    ap.add_argument("--decode", type=int, default=2)
+    ap.add_argument("--prefill", type=int, default=1)
+    ap.add_argument("--decode", type=int, default=1)
+    ap.add_argument("--hw", default=None, choices=sorted(A.PROFILES),
+                    help="live: the part the virtual clock bills (default: "
+                         "the device's own, by device_kind; a CPU run "
+                         "must name one)")
     ap.add_argument("--system", default="banaserve",
                     choices=("banaserve", "distserve", "vllm"))
     ap.add_argument("--workload", default="alpaca",
@@ -131,12 +154,12 @@ def main() -> None:
                          "decode orders land on the highest-HBM-bw part, "
                          "prefill on the highest-FLOPs part")
     args = ap.parse_args()
+    enable_compile_cache()
 
     backend, wl, tscale = (_build_live if args.backend == "live"
                            else _build_sim)(args)
     autoscaler = None
     if args.autoscale:
-        from ..core import analytical as A
         from ..serving.autoscale import AutoscaleConfig
         menu = (tuple(A.PROFILES[p] for p in args.profiles.split(","))
                 if args.profiles else None)

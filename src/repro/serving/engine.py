@@ -57,7 +57,7 @@ class EngineConfig:
     greedy: bool = True
     # paged decode via the page-fused split-KV Pallas kernel.  None = auto:
     # the kernel is the default whenever the cache is paged (compiled on
-    # TPU, interpret=True elsewhere — kernels/ops picks per backend).
+    # TPU, interpret=True on the CPU — kernels/ops picks per backend).
     # False forces the gather-then-attend dense reference path (kept as
     # the bit-level A/B baseline); True forces the kernel.
     decode_kernel: Optional[bool] = None
@@ -460,6 +460,7 @@ class PrefillEngine:
 
             {"rows": padded row count, "padded_len": padded suffix length,
              "tokens": prompt tokens actually computed this wave,
+             "resumed": rows resuming a parked chunk partial,
              "done": [(index into reqs, request_state, last_logits_row)]}
 
         Request states in ``done`` are in the paged wire format when the
@@ -515,6 +516,7 @@ class PrefillEngine:
             # the engine's capacity contract: never a denser forward than
             # the configured batch; the wave loop picks up the overflow
             chosen = chosen[: max(self.ecfg.max_batch, 1)]
+            n_resumed = sum(i in partials for i in chosen)
             n_rows = len(chosen)
             wave_frames = frames
             if self._pad and (wave_frames is None
@@ -685,7 +687,8 @@ class PrefillEngine:
             done = {i for i, _, _ in done_wave}
             remaining = [i for i in remaining if i not in done]
             yield {"rows": n_rows, "padded_len": blen,
-                   "tokens": wave_tokens, "done": done_wave}
+                   "tokens": wave_tokens, "resumed": n_resumed,
+                   "done": done_wave}
 
     def run_batch(self, reqs: List[Request],
                   frames: Optional[jax.Array] = None,

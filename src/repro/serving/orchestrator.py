@@ -45,6 +45,7 @@ import dataclasses
 from collections import deque
 from typing import Deque, Dict, List, Optional, Sequence, Set
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -97,7 +98,10 @@ class OrchestratorConfig:
     control_interval: Optional[float] = None
     controller: ControllerConfig = ControllerConfig(
         delta_up=0.5, delta_down=0.25, rho=0.5, max_actions_per_cycle=2)
-    hw: A.HardwareProfile = A.TPU_V5E
+    # the part the virtual clock bills; None = the part the fleet runs on
+    # (``analytical.device_profile`` of device 0, which every member
+    # shares).  CPU runs model a part and must name it.
+    hw: Optional[A.HardwareProfile] = None
     # heterogeneous fleets: per-member profiles cycled over the initial
     # fleet (prefill members first, then decode).  None = homogeneous
     # ``hw``.  Each member's event costs, store-fetch overlap and
@@ -180,6 +184,9 @@ class Orchestrator(BackendBase):
             raise ValueError("fleet needs >=1 prefill and >=1 decode "
                              f"instance, got {ocfg.n_prefill}p/"
                              f"{ocfg.n_decode}d")
+        if ocfg.hw is None:
+            ocfg = dataclasses.replace(
+                ocfg, hw=A.device_profile(jax.devices()[0]))
         self.cfg = cfg
         self.params = params
         self.ocfg = ocfg
@@ -239,6 +246,8 @@ class Orchestrator(BackendBase):
                                and self.store is not None
                                and KC.prefix_cacheable(cfg))
         self.pages_bound = 0           # prefix pages bound by reference
+        # prefill waves that resumed at least one parked chunk partial
+        self.chunk_resume_waves = 0
         self.bound_bytes_saved = 0.0   # hand-off bytes the binds skipped
         if self.prefix_sharing:
             for m in self.decode_members():
@@ -784,6 +793,7 @@ class Orchestrator(BackendBase):
             m._wavegen = None
             m._batch = []
             return
+        self.chunk_resume_waves += wave["resumed"] > 0
         done = [(m._batch[i], st, lg) for i, st, lg in wave["done"]]
         m._wave_left -= len(done)
         if m._wave_left <= 0:
@@ -1275,6 +1285,7 @@ class Orchestrator(BackendBase):
         s["virtual_time_s"] = self.clock.now
         s["events"] = self.clock.n_processed
         s["chunk_tokens"] = self.ocfg.chunk_tokens
+        s["chunk_resume_waves"] = self.chunk_resume_waves
         s["span_moves"] = len(self.span_move_log)
         s["span_bytes_moved"] = sum(r["weight_bytes"] + r["kv_bytes"]
                                     for r in self.span_move_log)

@@ -400,6 +400,7 @@ def test_live_scale_down_drain_is_token_bit_identical(tiny_params,
 
     def fleet():
         return Orchestrator(TINY, tiny_params, OrchestratorConfig(
+            hw=A.TPU_V5E,
             n_prefill=1, n_decode=2, engine=TINY_ECFG, chunk_tokens=8))
 
     ref_srv = Server(fleet())

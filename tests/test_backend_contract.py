@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 from conftest import TINY, TINY_ECFG, assert_pools_restored
+from repro.core.analytical import TPU_V5E
 from repro.serving.api import Server
 from repro.serving.cluster import ClusterSim, SimConfig
 from repro.serving.orchestrator import Orchestrator, OrchestratorConfig
@@ -48,6 +49,7 @@ def make_backend(request, tiny_params):
     def make(**kw):
         if kind == "live":
             return Orchestrator(TINY, tiny_params, OrchestratorConfig(
+                hw=TPU_V5E,
                 n_prefill=2, n_decode=2, engine=TINY_ECFG, chunk_tokens=8,
                 **kw))
         return ClusterSim(SimConfig(model=TINY, mode="banaserve",
@@ -327,6 +329,7 @@ def test_attainment_denominator_is_explicit():
 
 def _fresh_orch(tiny_params, **kw):
     return Orchestrator(TINY, tiny_params, OrchestratorConfig(
+        hw=TPU_V5E,
         n_prefill=2, n_decode=2, engine=TINY_ECFG, chunk_tokens=8, **kw))
 
 

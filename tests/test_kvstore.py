@@ -40,6 +40,21 @@ def test_fetch_promotes_and_counts_latency():
     assert all(e.tier == 0 or e.nbytes == 100 for e in st._entries.values())
 
 
+def test_overlapped_fetch_rejects_malformed_request_state():
+    """A request-state payload (it carries "groups") that the per-layer
+    schedule cannot read is an error, not an opaque block billed
+    serially; opaque payloads still bill serially."""
+    st = GlobalKVStore(block_size=4)
+    toks = list(range(8))
+    layer = {"k": np.zeros((2, 4, 1, 8), np.float32)}
+    st.insert(toks, [{"groups": (layer,)}, "opaque"], nbytes_per_block=100)
+    _, keys = st.match(toks)
+    with pytest.raises(KeyError, match="rem"):
+        st.fetch(keys[:1], t_layer_compute=1e-3)
+    payloads, lat = st.fetch(keys[1:], t_layer_compute=1e-3)
+    assert payloads == ["opaque"] and lat > 0
+
+
 def test_eviction_cascade_drops_from_last_tier():
     st = GlobalKVStore(block_size=4, tiers=[
         TierSpec("hbm", 200, 100.0), TierSpec("host", 200, 1.0)])

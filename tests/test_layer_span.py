@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from conftest import TINY, TINY_ECFG
+from repro.core.analytical import TPU_V5E
 from repro.core.layer_migration import even_spans
 from repro.core.migration import MigrationAction, MigrationKind
 from repro.models.config import BlockKind, Family, ModelConfig
@@ -191,6 +192,7 @@ def test_controller_never_prices_stage_reroll(tiny_params, make_workload):
     action applied on a split fleet is a same-pipeline span move."""
     from repro.core.migration import DeviceLoad
     orch = Orchestrator(TINY, tiny_params, OrchestratorConfig(
+        hw=TPU_V5E,
         n_prefill=2, n_decode=1, engine=TINY_ECFG, migration=True,
         decode_split=2))
     hot = DeviceLoad(device="decode0.0", compute_frac=1.0, memory_frac=1.0)
@@ -260,6 +262,7 @@ def test_orchestrator_span_move_before_and_after_exact(tiny_params,
     live MigrationKind.LAYER span move applied mid-run, the move re-cuts
     the pipeline instead of re-rolling, and the payload is logged."""
     orch = Orchestrator(TINY, tiny_params, OrchestratorConfig(
+        hw=TPU_V5E,
         n_prefill=1, n_decode=1, engine=TINY_ECFG, migration=False,
         decode_split=2))
     assert orch.fleet == {"prefill0": "prefill", "decode0.0": "decode",
@@ -290,6 +293,7 @@ def test_orchestrator_span_stages_never_reroll(tiny_params):
     """LAYER actions between a pipeline stage and anything outside its
     pipeline are refused — stages re-slice spans, not roles."""
     orch = Orchestrator(TINY, tiny_params, OrchestratorConfig(
+        hw=TPU_V5E,
         n_prefill=1, n_decode=2, engine=TINY_ECFG, migration=False,
         decode_split=2))
     act = MigrationAction(MigrationKind.LAYER, src="decode0.1",
@@ -310,6 +314,7 @@ def test_orchestrator_rebalance_across_pipelines(tiny_params,
     """KV_HEADS between two pipelines WITH DIFFERENT BOUNDS: slots merge
     to the wire format on exit and re-split at the target's cuts."""
     orch = Orchestrator(TINY, tiny_params, OrchestratorConfig(
+        hw=TPU_V5E,
         n_prefill=1, n_decode=2, engine=TINY_ECFG, migration=False,
         decode_split=2))
     # skew the second pipeline's cuts so the wire format must re-slice

@@ -1,11 +1,15 @@
 """Live orchestrator: routing over real engines, KV hand-off, and
 migration re-rolls must all preserve token-for-token greedy decode."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.migration import MigrationAction, MigrationKind
+from repro.core.analytical import TPU_V5E
+from repro.core.migration import (ControllerConfig, MigrationAction,
+                                  MigrationKind)
 from repro.models.config import Family, ModelConfig
 from repro.serving.engine import DecodeEngine, EngineConfig, PrefillEngine
 from repro.serving.orchestrator import (ROLE_DECODE, ROLE_PREFILL,
@@ -57,6 +61,17 @@ def _workload(n, seed=3, max_new=8):
 # ---------------------------------------------------------------------------
 # Batched prefill (engine-level)
 # ---------------------------------------------------------------------------
+
+def test_default_config_constructs_with_frozen_controller():
+    """The orchestrator's defaults are shared instances, so every config
+    they hold is frozen: the default controller config cannot be mutated
+    through one fleet under another."""
+    ocfg = OrchestratorConfig()
+    assert ocfg.hw is None        # resolved from the fleet's device
+    with pytest.raises(dataclasses.FrozenInstanceError):
+        ocfg.controller.delta_up = 0.9
+    assert ControllerConfig().delta_up == 0.35
+
 
 def test_batched_prefill_matches_single(params):
     """One dense batch — mixed prefix hit/miss rows — equals per-request
@@ -144,6 +159,7 @@ def test_round_trip_matches_reference(params, _reference_rollout):
     chunked prefill): every request's greedy decode equals the monolithic
     rollout under the event-driven virtual-clock loop."""
     orch = Orchestrator(CFG, params, OrchestratorConfig(
+        hw=TPU_V5E,
         n_prefill=2, n_decode=2, engine=ECFG, chunk_tokens=8))
     reqs = _workload(8, max_new=5)
     s = orch.run(reqs)
@@ -160,6 +176,7 @@ def test_round_trip_matches_reference(params, _reference_rollout):
 def test_router_balances_prefill(params):
     """Load-aware routing spreads work over >=2 prefill instances."""
     orch = Orchestrator(CFG, params, OrchestratorConfig(
+        hw=TPU_V5E,
         n_prefill=2, n_decode=2, engine=ECFG, migration=False))
     rng = np.random.default_rng(7)
     reqs = [Request(rid=i, arrival=0.0,
@@ -177,6 +194,7 @@ def test_forced_migration_changes_fleet_and_stays_exact(params):
     """A forced LAYER action re-rolls an instance between roles — including
     evacuating live decode KV — without perturbing any output."""
     orch = Orchestrator(CFG, params, OrchestratorConfig(
+        hw=TPU_V5E,
         n_prefill=2, n_decode=2, engine=ECFG, migration=False))
     reqs = _workload(6, seed=9, max_new=8)
     for r in reqs:
@@ -218,6 +236,7 @@ def test_forced_migration_changes_fleet_and_stays_exact(params):
 
 def test_floors_prevent_draining_a_role(params):
     orch = Orchestrator(CFG, params, OrchestratorConfig(
+        hw=TPU_V5E,
         n_prefill=1, n_decode=1, engine=ECFG, migration=False))
     act = MigrationAction(MigrationKind.LAYER, src="decode0", dst="prefill0",
                           amount=CFG.n_layers, predicted_benefit=1.0,
@@ -230,6 +249,7 @@ def test_controller_migrates_under_decode_pressure(params):
     """Decode-heavy load on a 3p/1d fleet makes Algorithm 1 re-roll idle
     prefill capacity into the decode tier — live, not simulated."""
     orch = Orchestrator(CFG, params, OrchestratorConfig(
+        hw=TPU_V5E,
         n_prefill=3, n_decode=1, engine=ECFG))
     reqs = _workload(10, seed=5, max_new=10)
     orch.run(reqs)
@@ -243,6 +263,7 @@ def test_controller_migrates_under_decode_pressure(params):
 def test_prefix_aware_baseline_runs_with_private_stores(params):
     """Baseline A/B config: per-instance stores + prefix-aware router."""
     orch = Orchestrator(CFG, params, OrchestratorConfig(
+        hw=TPU_V5E,
         n_prefill=2, n_decode=2, router="prefix_aware", global_store=False,
         engine=ECFG, migration=False))
     reqs = _workload(8, seed=11, max_new=4)

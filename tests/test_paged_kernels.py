@@ -26,6 +26,7 @@ import numpy as np
 import pytest
 
 from conftest import TINY, TINY_ECFG
+from repro.core.analytical import TPU_V5E
 from repro.kernels import ops
 from repro.kernels.flash_prefill import flash_prefill
 from repro.kernels.ref import (flash_prefill_reference,
@@ -459,6 +460,7 @@ def test_kernel_vs_dense_through_shared_prefix_orchestration(tiny_params):
             vocab_size=TINY.vocab_size, max_new_tokens=5, prefix_share=0.9,
             n_prefix_groups=1, seed=17, prompt_len_lo=16, prompt_len_hi=32))
         orch = Orchestrator(TINY, tiny_params, OrchestratorConfig(
+            hw=TPU_V5E,
             n_prefill=1, n_decode=1, migration=False,
             engine=dataclasses.replace(TINY_ECFG, decode_kernel=dk)))
         s = orch.run(reqs)

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from conftest import TINY, TINY_ECFG, assert_pools_restored
+from repro.core.analytical import TPU_V5E
 from repro.serving.api import Server
 from repro.serving.cluster import ClusterSim, SimConfig
 from repro.serving.fairshare import SchedulerConfig
@@ -22,6 +23,7 @@ from repro.serving.request import Outcome
 
 def _live(tiny_params, **kw):
     return Orchestrator(TINY, tiny_params, OrchestratorConfig(
+        hw=TPU_V5E,
         n_prefill=2, n_decode=2, engine=TINY_ECFG, chunk_tokens=8, **kw))
 
 

@@ -25,6 +25,7 @@ import numpy as np
 import pytest
 
 from conftest import TINY, TINY_ECFG, assert_pools_restored
+from repro.core.analytical import TPU_V5E
 from repro.core.kvstore import GlobalKVStore, chain_hashes
 from repro.core.layer_migration import even_spans
 from repro.models import kvcache as KC
@@ -363,6 +364,7 @@ def test_every_blockkind_shared_prefix_exact(cfg, model_zoo,
         max_new_tokens=5, prefix_share=0.9, n_prefix_groups=1, seed=11,
         prompt_len_lo=16, prompt_len_hi=32))
     orch = Orchestrator(cfg, params, OrchestratorConfig(
+        hw=TPU_V5E,
         n_prefill=1, n_decode=1, migration=False, engine=TINY_ECFG))
     summary = orch.run(reqs)
     for r in reqs:
@@ -388,6 +390,7 @@ def test_sharing_off_is_token_identical(tiny_params):
             vocab_size=TINY.vocab_size, max_new_tokens=5, prefix_share=0.9,
             n_prefix_groups=1, seed=13, prompt_len_lo=16, prompt_len_hi=32))
         orch = Orchestrator(TINY, tiny_params, OrchestratorConfig(
+            hw=TPU_V5E,
             n_prefill=1, n_decode=1, migration=False, engine=TINY_ECFG,
             prefix_sharing=sharing))
         s = orch.run(reqs)

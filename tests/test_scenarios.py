@@ -15,6 +15,7 @@ import jax
 import pytest
 
 from conftest import TINY, TINY_ECFG, assert_pools_restored
+from repro.core.analytical import TPU_V5E
 from repro.core.migration import MigrationKind
 from repro.serving.api import Server
 from repro.serving.cluster import ClusterSim, SimConfig
@@ -87,6 +88,7 @@ def _run(name, tiny_params, make_workload, greedy_reference, n_requests,
                                         seed)
     fleet_kw = {**fleet_kw, **fleet_extra}
     orch = Orchestrator(TINY, tiny_params, OrchestratorConfig(
+        hw=TPU_V5E,
         engine=TINY_ECFG, **fleet_kw))
     server, handles = _drive(orch, reqs)
     s = server.summary()
@@ -148,6 +150,7 @@ def test_scenario_abort_leaves_no_page_leaks(tiny_params, make_workload,
     reqs, fleet_kw = _scenario_workload("prefix_skewed", make_workload,
                                         8, seed=17)
     orch = Orchestrator(TINY, tiny_params, OrchestratorConfig(
+        hw=TPU_V5E,
         engine=TINY_ECFG, **fleet_kw))
     server = Server(orch)
     ordered = sorted(reqs, key=lambda r: r.arrival)
@@ -275,6 +278,7 @@ def test_scenario_tenant_metrics_live(tiny_params, make_workload):
     for i, r in enumerate(reqs):
         r.tenant = "a" if i % 2 else "b"
     orch = Orchestrator(TINY, tiny_params, OrchestratorConfig(
+        hw=TPU_V5E,
         engine=TINY_ECFG, n_prefill=2, n_decode=2, chunk_tokens=16))
     server = Server(orch, scheduler=SchedulerConfig(
         policy="wfq", tenants={"a": TenantPolicy(weight=2.0),

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from conftest import TINY, TINY_ECFG
+from repro.core.analytical import TPU_V5E
 from repro.core.scheduling import InstanceLoad, LoadAwareRouter, RequestInfo
 from repro.serving.clock import VirtualClock
 from repro.serving.engine import PrefillEngine
@@ -222,6 +223,7 @@ def test_chunked_rollout_token_exact_under_orchestrator(tiny_params,
     monotone per request."""
     from repro.serving.orchestrator import Orchestrator, OrchestratorConfig
     orch = Orchestrator(TINY, tiny_params, OrchestratorConfig(
+        hw=TPU_V5E,
         n_prefill=2, n_decode=2, engine=TINY_ECFG, chunk_tokens=8))
     reqs = make_workload(6, seed=23, max_new=6, rps=1e7,
                          prompt_len_lo=24, prompt_len_hi=64)
@@ -242,6 +244,7 @@ def test_virtual_clock_runs_are_deterministic(tiny_params, make_workload):
 
     def once():
         orch = Orchestrator(TINY, tiny_params, OrchestratorConfig(
+            hw=TPU_V5E,
             n_prefill=2, n_decode=2, engine=TINY_ECFG, chunk_tokens=8,
             slo=SLO(ttft_s=5e-6, tpot_s=2e-6)))
         reqs = make_workload(8, seed=7, max_new=5, rps=1e7)

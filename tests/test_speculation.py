@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 from conftest import TINY, TINY_ECFG, assert_pools_restored
+from repro.core.analytical import TPU_V5E
 from repro.models import transformer as T
 from repro.models.config import Family, ModelConfig
 from repro.serving.api import Server
@@ -240,6 +241,7 @@ def _orch(tiny_params, speculation="off", **kw):
     ecfg = dataclasses.replace(TINY_ECFG, speculation=speculation,
                                spec_len=3)
     return Orchestrator(TINY, tiny_params, OrchestratorConfig(
+        hw=TPU_V5E,
         n_prefill=1, n_decode=2, engine=ecfg, chunk_tokens=8, **kw))
 
 
@@ -306,6 +308,7 @@ def test_speculation_gated_on_span_pipelines(tiny_params, make_workload):
         reqs = make_workload(n=5, seed=19, max_new=6)
         ecfg = dataclasses.replace(TINY_ECFG, speculation=spec)
         orch = Orchestrator(TINY, tiny_params, OrchestratorConfig(
+            hw=TPU_V5E,
             n_prefill=1, n_decode=1, decode_split=2, engine=ecfg,
             chunk_tokens=8))
         for pipe in orch.decode_pipes:
