@@ -31,17 +31,12 @@ class Event:
 
 
 class VirtualClock:
-    """Heap-based event queue with a monotonic virtual ``now``.
+    """Heap-based event queue with a monotonic virtual ``now``."""
 
-    ``trace=True`` keeps a per-event ``(t, kind)`` log — the execution
-    trace tests and the docs' event-loop diagram refer to.
-    """
-
-    def __init__(self, trace: bool = False):
+    def __init__(self):
         self.now: float = 0.0
         self._heap: List[Tuple[float, int, Event]] = []
         self._seq = 0
-        self.trace: Optional[List[Tuple[float, str]]] = [] if trace else None
         self.n_processed = 0
 
     def __len__(self) -> int:
@@ -75,6 +70,4 @@ class VirtualClock:
         _, _, ev = heapq.heappop(self._heap)
         self.now = ev.t
         self.n_processed += 1
-        if self.trace is not None:
-            self.trace.append((ev.t, ev.kind))
         return ev

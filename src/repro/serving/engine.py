@@ -46,6 +46,7 @@ from ..core.scheduling import LoadReport
 from ..models import kvcache as KC
 from ..models import transformer as T
 from ..models.config import ModelConfig
+from . import tracing
 from .request import Phase, Request
 
 
@@ -122,14 +123,21 @@ def _jit_apply(cfg: ModelConfig, mode: str, prefix_aware: bool,
     consumes the previous span's residual stream, ``hidden_out`` emits one
     for the next).  The cache is donated: decode updates its pools in place
     instead of copying them every step (callers never reuse the cache they
-    pass in)."""
-    return jax.jit(functools.partial(T.apply, cfg, mode=mode,
-                                     logits_slice=logits_slice,
-                                     prefix_aware=prefix_aware,
-                                     paged_kernel=paged_kernel,
-                                     hidden_in=hidden_in,
-                                     hidden_out=hidden_out),
-                   donate_argnames=("cache",))
+    pass in).
+
+    Each kind of forward compiles under its own program name
+    (``jit_prefill``, ``jit_prefill_prefix`` over a held prefix,
+    ``jit_decode``, ``jit_verify``), so a profile tells them apart."""
+    fwd = functools.partial(T.apply, cfg, mode=mode,
+                            logits_slice=logits_slice,
+                            prefix_aware=prefix_aware,
+                            paged_kernel=paged_kernel, hidden_in=hidden_in,
+                            hidden_out=hidden_out)
+    if mode == "prefill":
+        fwd.__name__ = "prefill_prefix" if prefix_aware else "prefill"
+    else:
+        fwd.__name__ = "verify" if logits_slice == "all" else "decode"
+    return jax.jit(fwd, donate_argnames=("cache",))
 
 
 def _span_view(cfg: ModelConfig, params,
@@ -245,6 +253,25 @@ class _Draft:
                 if t >= greedy_from[i] and len(outs[i]) < n_out:
                     outs[i].append(int(nxt[i]))
         return outs, n_steps
+
+
+@dataclasses.dataclass
+class _Waves:
+    """One ``prefill_waves`` batch, carried from wave to wave."""
+    reqs: List[Request]
+    toks: List[np.ndarray]
+    keys_of: List[List[bytes]]         # each prompt's block hash chain
+    chunk: Optional[int]
+    frames: Optional[jax.Array]
+    remaining: List[int]               # rows not yet done
+    partials: Dict[int, Dict[str, Any]] = dataclasses.field(
+        default_factory=dict)          # chunked rows mid-prompt
+    progress: Dict[int, int] = dataclasses.field(
+        default_factory=dict)          # tokens resident in partial
+    store_matched: Dict[int, int] = dataclasses.field(
+        default_factory=dict)          # store hit (for publish)
+    published: Dict[int, int] = dataclasses.field(
+        default_factory=dict)          # block-aligned publish mark
 
 
 class PrefillEngine:
@@ -461,6 +488,7 @@ class PrefillEngine:
             {"rows": padded row count, "padded_len": padded suffix length,
              "tokens": prompt tokens actually computed this wave,
              "resumed": rows resuming a parked chunk partial,
+             "hit": the rows resume a held prefix (store hit or chunk),
              "done": [(index into reqs, request_state, last_logits_row)]}
 
         Request states in ``done`` are in the paged wire format when the
@@ -480,12 +508,30 @@ class PrefillEngine:
         # disable the shared-prefix deferral below.
         keys_of = [chain_hashes(t, self.ecfg.block_size)
                    if self.store is not None else [] for t in toks]
-        partials: Dict[int, Dict[str, Any]] = {}  # chunked rows mid-prompt
-        progress: Dict[int, int] = {}             # tokens resident in partial
-        store_matched: Dict[int, int] = {}        # store hit (for publish)
-        published: Dict[int, int] = {}            # block-aligned publish mark
-        remaining = list(range(len(reqs)))
-        while remaining:
+        w = _Waves(reqs, toks, keys_of, chunk, frames,
+                   remaining=list(range(len(reqs))))
+        while w.remaining:
+            # the span closes before the yield: what the caller does
+            # between two waves is not the wave's
+            with tracing.span("prefill.wave") as sp:
+                wave = self._wave(w)
+                sp.set_metadata(rows=wave["rows"],
+                                padded_len=wave["padded_len"],
+                                tokens=wave["tokens"],
+                                resumed=wave["resumed"],
+                                hit=int(wave["hit"]))
+            yield wave
+
+    def _wave(self, w: _Waves) -> Dict[str, Any]:
+        """One wave of ``prefill_waves``: re-match and bucket the rows
+        left, stage the chosen bucket's cache, run its forward, extract
+        each row's state (publishing its new full blocks); returns the
+        wave's record."""
+        reqs, toks, keys_of, chunk = w.reqs, w.toks, w.keys_of, w.chunk
+        partials, progress = w.partials, w.progress
+        store_matched, published = w.store_matched, w.published
+        remaining, frames = w.remaining, w.frames
+        with tracing.span("prefill.match"):
             tlen = {i: progress[i] if i in partials
                     else self._match_len(toks[i], keys_of[i])
                     for i in remaining}
@@ -533,13 +579,14 @@ class PrefillEngine:
                                   + wave_frames.shape[1:],
                                   wave_frames.dtype)])
                 n_rows = padded_rows
-            chain = [self] + self._followers
-            # hit waves on pageable single-span stacks run PAGED: the
-            # cached prefix lives in pool pages the fused prefill kernel
-            # reads through the block table — no per-wave dense re-gather
-            use_paged = hit and len(chain) == 1 and self._paged_inc
-            bs = self.ecfg.block_size
-            matched_of: Dict[int, int] = {}
+        chain = [self] + self._followers
+        # hit waves on pageable single-span stacks run PAGED: the cached
+        # prefix lives in pool pages the fused prefill kernel reads
+        # through the block table — no per-wave dense re-gather
+        use_paged = hit and len(chain) == 1 and self._paged_inc
+        bs = self.ecfg.block_size
+        matched_of: Dict[int, int] = {}
+        with tracing.span("prefill.stage"):
             if use_paged:
                 nb_slot = self._page_len // bs
                 pcache = T.init_paged_cache(
@@ -624,6 +671,7 @@ class PrefillEngine:
             self.prefill_shapes.add((n_rows, blen, hit))
             la = jnp.asarray(slens - 1)
             x: jax.Array = jnp.asarray(suffix)
+        with tracing.span("prefill.forward"):
             for k, e in enumerate(chain):
                 if len(chain) == 1:
                     fn = self._prefill_inc if hit else self._prefill
@@ -635,9 +683,10 @@ class PrefillEngine:
                                     hidden_out=k < len(chain) - 1)
                 x, caches[k], _ = fn(e.sparams, x, cache=caches[k],
                                      frames=wave_frames, logits_at=la)
-            logits = x
-            done_wave: List[Tuple[int, Dict[str, Any], jax.Array]] = []
-            wave_tokens = 0
+        logits = x
+        done_wave: List[Tuple[int, Dict[str, Any], jax.Array]] = []
+        wave_tokens = 0
+        with tracing.span("prefill.extract"):
             for row, i in enumerate(chosen):
                 # the cache advanced by the padded length; the request's
                 # true length is what decode must resume from
@@ -667,7 +716,8 @@ class PrefillEngine:
                 pub_from = published.get(i, store_matched.get(i, 0))
                 keys_part = keys_of[i][: new_len // self.ecfg.block_size]
                 if len(keys_part) * self.ecfg.block_size > pub_from:
-                    self._publish(toks[i], st, pub_from, keys_part)
+                    with tracing.span("prefill.publish"):
+                        self._publish(toks[i], st, pub_from, keys_part)
                     published[i] = len(keys_part) * self.ecfg.block_size
                 if new_len < len(toks[i]):
                     # chunk boundary: park the partial state, stay
@@ -684,11 +734,11 @@ class PrefillEngine:
                 if self._page_len is not None and "n_blocks" not in st:
                     st = KC.dense_state_to_paged(st, bs)
                 done_wave.append((i, st, logits[row]))
-            done = {i for i, _, _ in done_wave}
-            remaining = [i for i in remaining if i not in done]
-            yield {"rows": n_rows, "padded_len": blen,
-                   "tokens": wave_tokens, "resumed": n_resumed,
-                   "done": done_wave}
+        done = {i for i, _, _ in done_wave}
+        w.remaining = [i for i in remaining if i not in done]
+        return {"rows": n_rows, "padded_len": blen,
+                "tokens": wave_tokens, "resumed": n_resumed,
+                "hit": hit, "done": done_wave}
 
     def run_batch(self, reqs: List[Request],
                   frames: Optional[jax.Array] = None,
@@ -1167,17 +1217,24 @@ class DecodeEngine:
         multi-query pass and commits the longest greedy-identical prefix
         plus the verifier's own bonus token — between 1 and spec_len+1
         tokens per iteration, bit-identical to plain greedy decode."""
-        if self.active == 0:
+        rows = self.active
+        if rows == 0:
             return []
-        if self.spec_on and self._spec_ok:
-            out = self._spec_step()
-            if out is not None:
-                return out
-        self.decode_iters += 1
-        self._prepare_pages()
-        logits = self._forward_step(jnp.asarray(self.next_token[:, None]))
-        nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        return self.commit(nxt)
+        with tracing.span("decode.step", rows=rows):
+            if self.spec_on and self._spec_ok:
+                out = self._spec_step()
+                if out is not None:
+                    return out
+            self.decode_iters += 1
+            with tracing.span("decode.prepare"):
+                self._prepare_pages()
+            with tracing.span("decode.forward"):
+                logits = self._forward_step(
+                    jnp.asarray(self.next_token[:, None]))
+            with tracing.span("decode.sync"):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            with tracing.span("decode.commit"):
+                return self.commit(nxt)
 
     # -- speculative decoding -------------------------------------------
     def _commit_slot(self, i: int, toks: List[int]) -> bool:

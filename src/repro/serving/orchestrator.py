@@ -59,6 +59,7 @@ from ..core.scheduling import (LoadAwareRouter, PrefixAwareRouter,
                                live_instance_loads, utilization_gap)
 from ..models import kvcache as KC
 from ..models.config import ModelConfig
+from . import tracing
 from .api import BackendBase
 from .clock import VirtualClock
 from .engine import DecodeEngine, EngineConfig, PrefillEngine
@@ -123,7 +124,6 @@ class OrchestratorConfig:
     decode_split: int = 1
     slo: Optional[SLO] = None      # TTFT/TPOT targets for goodput accounting
     efficiency: float = 0.5        # prefill MFU for event costs (Eq. 20)
-    trace_events: bool = False     # keep the clock's per-event (t, kind) log
 
 
 class _Member:
@@ -249,6 +249,9 @@ class Orchestrator(BackendBase):
         # prefill waves that resumed at least one parked chunk partial
         self.chunk_resume_waves = 0
         self.bound_bytes_saved = 0.0   # hand-off bytes the binds skipped
+        self.pages_moved = 0           # pages the hand-offs copied
+        self.handoff_bytes_moved = 0   # bytes the hand-offs copied
+        self.migration_bytes = 0       # KV and weight bytes migrations moved
         if self.prefix_sharing:
             for m in self.decode_members():
                 if m.pipe is None and m.decode.paged:
@@ -256,7 +259,7 @@ class Orchestrator(BackendBase):
         self.controller = (MigrationController(ocfg.controller,
                                                self._migration_cost)
                            if ocfg.migration else None)
-        self.clock = VirtualClock(trace=ocfg.trace_events)
+        self.clock = VirtualClock()
         self.control_interval = (
             float(ocfg.control_interval) if ocfg.control_interval is not None
             else 2.0 * A.decode_iter_time(cfg, self.ecfg.max_len, ocfg.hw,
@@ -737,25 +740,26 @@ class Orchestrator(BackendBase):
 
     # -- event handlers ---------------------------------------------------
     def _handle(self, ev) -> List[Request]:
-        if ev.kind == "arrival":
-            if self._admit(ev.payload):   # bounced: aborted or queue full
-                self.pending.append(ev.payload)
-                self._dispatch()
-        elif ev.kind == "prefill":
-            self._on_prefill(ev.payload)
-        elif ev.kind == "prefill_done":
-            self._on_prefill_done(*ev.payload)
-        elif ev.kind == "decode_kick":
-            self._kick_decode(self._unit_by_name(ev.payload))
-        elif ev.kind == "decode_done":
-            return self._on_decode_done(*ev.payload)
-        elif ev.kind == "control":
-            self._on_control()
-        elif ev.kind == "warmed":
-            self._on_warmed(ev.payload)
-        else:
-            raise ValueError(f"unknown event kind {ev.kind!r}")
-        return []
+        with tracing.span("event." + ev.kind):
+            if ev.kind == "arrival":
+                if self._admit(ev.payload):   # bounced: aborted or queue full
+                    self.pending.append(ev.payload)
+                    self._dispatch()
+            elif ev.kind == "prefill":
+                self._on_prefill(ev.payload)
+            elif ev.kind == "prefill_done":
+                self._on_prefill_done(*ev.payload)
+            elif ev.kind == "decode_kick":
+                self._kick_decode(self._unit_by_name(ev.payload))
+            elif ev.kind == "decode_done":
+                return self._on_decode_done(*ev.payload)
+            elif ev.kind == "control":
+                self._on_control()
+            elif ev.kind == "warmed":
+                self._on_warmed(ev.payload)
+            else:
+                raise ValueError(f"unknown event kind {ev.kind!r}")
+            return []
 
     def _on_prefill(self, name: str) -> None:
         """One prefill wave: pick up a batch if idle, run the next dense
@@ -816,34 +820,51 @@ class Orchestrator(BackendBase):
                 continue
             if req.outcome is not None:
                 continue       # aborted mid-prefill: its KV is dropped here
-            req.advance(Phase.TRANSFER)
-            # ties broken by unit name so target selection is
-            # deterministic across re-rolls and fleet orderings
-            tgt = min((u for u in self._placeable_units()
-                       if u.free_slots > 0),
-                      key=lambda u: (u.active, u.kv_tokens, u.name))
-            shared: List[int] = []
-            keys: List[bytes] = []
-            if self._sharing_target(tgt):
-                keys = chain_hashes(req.prompt, self.ecfg.block_size)
-                st, shared = self._bind_shared(req, st, tgt, keys)
-            # the hand-off bills only the pages that actually move — a
-            # bound prefix crosses as references, not bytes
-            t_ov = self._account_handoff(req, st)
-            slot = tgt.insert(req, st, int(jnp.argmax(logits)),
-                              shared_pages=shared or None)
-            if keys:
-                self._register_prefix(req, tgt, slot, keys)
-            # the first token becomes visible once its KV hand-off's
-            # overlapped per-layer schedule completes
-            req.t_first_token = self.clock.now + t_ov
-            req.t_tokens.append(req.t_first_token)
-            self.clock.push_in(t_ov, "decode_kick", tgt.name)
+            with tracing.span("handoff", rid=req.rid) as sp:
+                self._handoff(req, st, logits, sp)
         if m is not None and m.role == ROLE_PREFILL and \
                 (m._wavegen is not None or m.prefill.queue):
             self.clock.push(self.clock.now, "prefill", m.name)
         if m is not None and m.draining:
             self._try_retire_member(m)
+
+    def _handoff(self, req: Request, st: Dict, logits, sp) -> None:
+        """Move one prefilled request into a decode slot: bind the pages
+        the target already holds, copy the rest, and schedule the first
+        decode iteration.  ``sp`` is the request's ``handoff`` span."""
+        req.advance(Phase.TRANSFER)
+        # ties broken by unit name so target selection is
+        # deterministic across re-rolls and fleet orderings
+        tgt = min((u for u in self._placeable_units()
+                   if u.free_slots > 0),
+                  key=lambda u: (u.active, u.kv_tokens, u.name))
+        shared: List[int] = []
+        keys: List[bytes] = []
+        with tracing.span("handoff.bind"):
+            if self._sharing_target(tgt):
+                keys = chain_hashes(req.prompt, self.ecfg.block_size)
+                st, shared = self._bind_shared(req, st, tgt, keys)
+        moved, nbytes = st.get("n_blocks", 0), KC.state_num_bytes(st)
+        self.pages_moved += moved
+        self.handoff_bytes_moved += nbytes
+        sp.set_metadata(pages_bound=len(shared), pages_moved=moved,
+                        bytes_moved=nbytes)
+        # the hand-off bills only the pages that actually move — a
+        # bound prefix crosses as references, not bytes
+        t_ov = self._account_handoff(req, st)
+        with tracing.span("handoff.wait"):
+            # the first token's readback waits for the wave's device work
+            first = int(jnp.argmax(logits))
+        with tracing.span("handoff.insert"):
+            slot = tgt.insert(req, st, first, shared_pages=shared or None)
+        if keys:
+            with tracing.span("handoff.register"):
+                self._register_prefix(req, tgt, slot, keys)
+        # the first token becomes visible once its KV hand-off's
+        # overlapped per-layer schedule completes
+        req.t_first_token = self.clock.now + t_ov
+        req.t_tokens.append(req.t_first_token)
+        self.clock.push_in(t_ov, "decode_kick", tgt.name)
 
     def _on_decode_done(self, name: str, epoch: int) -> List[Request]:
         self._unit_busy.discard(name)
@@ -1190,6 +1211,13 @@ class Orchestrator(BackendBase):
         LAYER between adjacent stages of one decode pipeline = live span
         move of ``act.amount`` boundary layers; LAYER between full-stack
         members = whole-instance role re-roll."""
+        with tracing.span("migrate", kind=act.kind.value) as sp:
+            before = self.migration_bytes
+            ok = self._apply(act)
+            sp.set_metadata(bytes=self.migration_bytes - before)
+        return ok
+
+    def _apply(self, act: MigrationAction) -> bool:
         src = self._by_name.get(act.src)
         dst = self._by_name.get(act.dst)
         if src is None or dst is None:
@@ -1201,6 +1229,8 @@ class Orchestrator(BackendBase):
                 ok = res is not None
                 if ok:
                     self.span_move_log.append(res)
+                    self.migration_bytes += res["weight_bytes"] \
+                        + res["kv_bytes"]
             elif src.pipe is None and dst.pipe is None:
                 ok = self._reroll(dst, src.role)
             else:
@@ -1240,6 +1270,7 @@ class Orchestrator(BackendBase):
                 tgt = min((u for u in self._placeable_units()
                            if u is not member.unit and u.free_slots > 0),
                           key=lambda u: (u.active, u.name))
+                self.migration_bytes += KC.state_num_bytes(st)
                 tgt.adopt(req, st, tok)
             if self.store is not None:
                 # the pool's pages die with the engine: demote the store's
@@ -1271,6 +1302,7 @@ class Orchestrator(BackendBase):
             if s is None:
                 continue
             req, st, tok = su.extract_slot(slot)
+            self.migration_bytes += KC.state_num_bytes(st)
             du.adopt(req, st, tok)
             moved += 1
         return moved > 0
@@ -1329,6 +1361,8 @@ class Orchestrator(BackendBase):
             s["prefix_sharing"] = self.prefix_sharing
             s["pages_bound"] = self.pages_bound
             s["bound_bytes_saved"] = self.bound_bytes_saved
+            s["pages_moved"] = self.pages_moved
+            s["handoff_bytes_moved"] = self.handoff_bytes_moved
             s["cow_forks"] = sum(
                 m.decode.cow_forks for m in self.decode_members()
                 if m.decode is not None)

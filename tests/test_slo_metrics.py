@@ -76,7 +76,7 @@ def test_metrics_without_slo_reports_nan_attainment():
 # ---------------------------------------------------------------------------
 
 def test_clock_orders_by_time_then_fifo():
-    ck = VirtualClock(trace=True)
+    ck = VirtualClock()
     ck.push(2.0, "b")
     ck.push(1.0, "a1")
     ck.push(1.0, "a2")        # same timestamp: FIFO
@@ -86,7 +86,6 @@ def test_clock_orders_by_time_then_fifo():
         kinds.append(ck.pop().kind)
     assert kinds == ["first", "a1", "a2", "b"]
     assert ck.now == 2.0
-    assert [k for _, k in ck.trace] == kinds
     assert ck.n_processed == 4
 
 
