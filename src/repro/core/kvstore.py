@@ -120,6 +120,14 @@ class _Entry:
         self.page = None       # physical page index in that pool
 
 
+@dataclasses.dataclass(frozen=True)
+class PageRef:
+    """A page-resident entry as ``fetch_pages`` returns it: the registered
+    pool and the physical page that holds the block."""
+    pool: Any
+    page: int
+
+
 class GlobalKVStore:
     """Cluster-wide prefix KV cache with tiered capacity + LRU eviction."""
 
@@ -177,16 +185,31 @@ class GlobalKVStore:
         non-overlapped residual — the pipeline makespan minus the compute
         that runs regardless (Eq. 12–17) — is billed, so a fetch hidden
         under per-layer compute costs ~nothing."""
+        return self._fetch(keys, t_layer_compute, pages=False)
+
+    def fetch_pages(self, keys: Sequence[bytes],
+                    t_layer_compute: Optional[float] = None
+                    ) -> Tuple[List[Any], float]:
+        """``fetch`` without the copy out of the pool: a page-resident
+        entry comes back as a ``PageRef``, so the caller can move a whole
+        run of pages in one program; other entries come back as their
+        payloads.  Billing, stats and tier promotion are ``fetch``'s."""
+        return self._fetch(keys, t_layer_compute, pages=True)
+
+    def _fetch(self, keys: Sequence[bytes], t_layer_compute: Optional[float],
+               pages: bool) -> Tuple[List[Any], float]:
         payloads, latency = [], 0.0
         per_layer: Dict[int, float] = {}
         for k in keys:
             e = self._entries[k]
-            if e.pool is not None:
+            if e.pool is None:
+                payloads.append(e.payload)
+            elif pages:
+                payloads.append(PageRef(self._pools[e.pool], e.page))
+            else:
                 # page-resident: materialize a copy out of the live pool
                 # (HBM-tier read; the page itself stays shared in place)
                 payloads.append(self._pools[e.pool].materialize(e.page))
-            else:
-                payloads.append(e.payload)
             bw = self.tiers[e.tier].bandwidth_gbps * 1e9
             sched = (self._layer_schedule(e, payloads[-1])
                      if t_layer_compute is not None else None)
@@ -219,8 +242,11 @@ class GlobalKVStore:
         payload the schedule cannot read raises: billing it as opaque would
         hide a corrupt entry.  ``payload`` is passed in because
         page-resident entries materialize theirs per fetch (the schedule
-        shape is stable, so memoizing on the entry stays valid)."""
+        shape is stable, so memoizing on the entry stays valid); for a
+        ``PageRef`` the pool's page shapes stand in, with no copy."""
         if e.sched is None:
+            if isinstance(payload, PageRef):
+                payload = payload.pool.page_spec()
             e.sched = ()
             if isinstance(payload, dict) and "groups" in payload:
                 from ..models.kvcache import layer_transfer_schedule
@@ -258,8 +284,10 @@ class GlobalKVStore:
     def attach_pool(self, pool_id: str, pool: Any) -> None:
         """Register a block pool the store may hold page references into.
         ``pool`` must expose ``ref_pages(pages)``, ``unref_pages(pages) ->
-        freed`` and ``materialize(page) -> payload`` (the decode engines
-        do)."""
+        freed`` and ``materialize(page) -> payload``, and for
+        ``fetch_pages`` also ``page_spec()`` (the shapes of one
+        ``materialize`` payload) and ``cache`` (its paged cache); the
+        decode engines do."""
         self._pools[pool_id] = pool
 
     def register_pages(self, keys: Sequence[bytes], pool_id: str,

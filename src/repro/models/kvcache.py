@@ -36,10 +36,13 @@ Zero-copy prefix sharing (the vLLM/Mooncake block-sharing scheme):
   bind it.
 * ``copy_pages`` — jitted copy-on-write fork: duplicate pages inside one
   pool (a writer forks a shared page before the step touches it).
+* ``copy_pool_pages`` — copy pages from one pool into another (a store
+  hit's pool-resident prefix into a prefill wave's pool), in one program.
 * ``split_paged_state`` — drop the leading pages of a paged wire state
   (they are bound by reference instead of scattered).
 * ``page_payload`` — one physical page as a dense per-block store payload
-  (the demotion path out of HBM into the backing tiers).
+  (the demotion path out of HBM into the backing tiers); ``page_payloads``
+  does many pages in one program (a prefill wave's publishing).
 
 Only attention KV leaves (``k``/``v``/``pos`` + int8 scales) whose cache
 length equals the stack's page length (the longest attention cache) are
@@ -518,6 +521,38 @@ def copy_pages(pcache: Cache, src_idx: jax.Array, dst_idx: jax.Array, *,
             "rem": tuple(conv(g, 0) for g in pcache["rem"])}
 
 
+def copy_pool_pages(dst: Cache, src: Cache, src_idx: jax.Array,
+                    dst_idx: jax.Array, n, *, block_size: int) -> Cache:
+    """Copy pages ``src_idx[:n]`` of pool ``src`` into pages
+    ``dst_idx[:n]`` of pool ``dst`` (two caches of one stack) across every
+    paged leaf.  Jit-compatible, with ``n`` traced: the index vectors may
+    be padded to one fixed length, so the compiled shape follows the two
+    pools alone.  Pages move one at a time in a loop: on the TPU a gather
+    over the page axis first copies the whole source pool.  Run donated,
+    the destination pages are written in place."""
+    batch = int(dst["block_tables"].shape[0])
+
+    def conv(j, d: Dict[str, Any], s: Dict[str, Any],
+             batch_axis: int) -> Dict[str, Any]:
+        out = dict(d)
+        for key, a in d.items():
+            if _is_pool_leaf(key, a, batch_axis, batch, block_size):
+                page = jax.lax.dynamic_slice_in_dim(s[key], src_idx[j], 1,
+                                                    batch_axis)
+                out[key] = jax.lax.dynamic_update_slice_in_dim(
+                    a, page, dst_idx[j], batch_axis)
+        return out
+
+    def body(j, gr):
+        return (tuple(conv(j, d, s, 1)
+                      for d, s in zip(gr[0], src["groups"])),
+                tuple(conv(j, d, s, 0) for d, s in zip(gr[1], src["rem"])))
+
+    groups, rem = jax.lax.fori_loop(0, n, body,
+                                    (dst["groups"], dst["rem"]))
+    return {**dst, "groups": groups, "rem": rem}
+
+
 def split_paged_state(st: RequestState, n_head_blocks: int,
                       block_size: int) -> RequestState:
     """Drop the first ``n_head_blocks`` pages from a paged wire state.
@@ -574,6 +609,28 @@ def page_payload(pcache: Cache, page: int, block_size: int) -> RequestState:
     }
 
 
+def page_payloads(pcache: Cache, pages: jax.Array, *,
+                  block_size: int) -> Tuple[RequestState, ...]:
+    """``page_payload`` of every page in ``pages`` (traced (n,) int32), in
+    one program.  A caller pads ``pages`` to a fixed length and keeps the
+    payloads it asked for, so the compiled shape follows the pool alone,
+    not the number of pages."""
+    batch = int(pcache["block_tables"].shape[0])
+
+    def conv(g: Dict[str, Any], batch_axis: int) -> Dict[str, Any]:
+        return {key: a[(slice(None),) * batch_axis + (pages,)]
+                for key, a in g.items()
+                if _is_pool_leaf(key, a, batch_axis, batch, block_size)}
+
+    groups = tuple(conv(g, 1) for g in pcache["groups"])
+    rem = tuple(conv(g, 0) for g in pcache["rem"])
+    return tuple({"length": jnp.asarray(block_size, jnp.int32),
+                  "groups": tuple({k: a[:, j] for k, a in g.items()}
+                                  for g in groups),
+                  "rem": tuple({k: a[j] for k, a in g.items()} for g in rem)}
+                 for j in range(int(pages.shape[0])))
+
+
 def pages_from_payloads(payloads: Sequence[RequestState],
                         length: int) -> RequestState:
     """Stack per-block store payloads (``slice_prefix_kv`` shape, one
@@ -602,33 +659,6 @@ def pages_from_payloads(payloads: Sequence[RequestState],
                         for gi in range(len(payloads[0]["groups"]))),
         "rem": tuple(conv([p["rem"][gi] for p in payloads], 0)
                      for gi in range(len(payloads[0]["rem"]))),
-    }
-
-
-def paged_state_block(st: RequestState, block: int,
-                      block_size: int) -> RequestState:
-    """One page of a paged wire state as a dense per-block store payload —
-    the exact shape ``slice_prefix_kv`` yields for that block, so paged
-    prefill publishes to the store without ever densifying the state."""
-    n = int(st["n_blocks"])
-    assert 0 <= block < n, (block, n)
-
-    def conv(g: Dict[str, Any], seq_axis: int) -> Dict[str, Any]:
-        out = {}
-        for key, a in g.items():
-            if (key in PAGED_KEYS and hasattr(a, "shape")
-                    and a.ndim == seq_axis + 2 + _LEAF_TAIL[key]
-                    and a.shape[seq_axis] == n
-                    and a.shape[seq_axis + 1] == block_size):
-                out[key] = a[(slice(None),) * seq_axis + (block,)]
-            else:
-                out[key] = a
-        return out
-
-    return {
-        "length": jnp.asarray(block_size, jnp.int32),
-        "groups": tuple(conv(g, 1) for g in st["groups"]),
-        "rem": tuple(conv(g, 0) for g in st["rem"]),
     }
 
 

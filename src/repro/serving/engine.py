@@ -41,7 +41,7 @@ import numpy as np
 
 from ..core import analytical as A
 from ..core import layer_migration as LM
-from ..core.kvstore import GlobalKVStore, chain_hashes
+from ..core.kvstore import GlobalKVStore, PageRef, chain_hashes
 from ..core.scheduling import LoadReport
 from ..models import kvcache as KC
 from ..models import transformer as T
@@ -161,6 +161,13 @@ _page_reset = jax.jit(KC.reset_page_positions,
                       static_argnames=("block_size",), donate_argnums=(0,))
 _page_copy = jax.jit(KC.copy_pages, static_argnames=("block_size",),
                      donate_argnums=(0,))
+# A prefill wave's store pages: a hit row's pool-resident prefix copied
+# pool to pool, and a row's new blocks cut into store payloads, one
+# program each.  Index vectors are padded to the row's table width, so
+# these specialize per pool shape only, never per page count.
+_pool_copy = jax.jit(KC.copy_pool_pages, static_argnames=("block_size",),
+                     donate_argnums=(0,))
+_page_payloads = jax.jit(KC.page_payloads, static_argnames=("block_size",))
 
 
 def ngram_propose(ctx: List[int], k: int, max_n: int = 3) -> List[int]:
@@ -370,9 +377,10 @@ class PrefillEngine:
                           layer_span=self.layer_span)
 
     # -- prefill ---------------------------------------------------------
-    def _match(self, tokens: np.ndarray,
-               keys: List[bytes]) -> Tuple[int, List[Any]]:
-        """Longest block-aligned cached prefix + its fetched payloads."""
+    def _match(self, tokens: np.ndarray, keys: List[bytes],
+               pages: bool = False) -> Tuple[int, List[Any]]:
+        """Longest block-aligned cached prefix + its fetched payloads
+        (``pages``: page-resident blocks as ``PageRef``s, uncopied)."""
         if self.store is None or len(tokens) < 2:
             return 0, []
         matched, hit_keys = self.store.match(tokens, keys=keys)
@@ -381,10 +389,46 @@ class PrefillEngine:
         if matched <= 0:
             return 0, []
         hit_keys = hit_keys[: matched // self.ecfg.block_size]
-        payloads, t_fetch = self.store.fetch(
-            hit_keys, t_layer_compute=self._t_layer_fetch)
+        fetch = self.store.fetch_pages if pages else self.store.fetch
+        payloads, t_fetch = fetch(hit_keys,
+                                  t_layer_compute=self._t_layer_fetch)
         self.fetch_latency_s += t_fetch
         return matched, payloads
+
+    def _stage_prefix(self, pcache: Dict[str, Any], row: int,
+                      items: List[Any], start: int
+                      ) -> Tuple[Dict[str, Any], int]:
+        """Write a store hit's blocks into wave pages ``start``,
+        ``start + 1``, ... of ``row``: page-resident blocks in one page
+        copy per source pool, its index vectors padded to the table width;
+        payload blocks (demoted to a backing tier, or published and not
+        yet handed off) stacked and scattered.  Returns the cache and the
+        pages copied."""
+        bs = self.ecfg.block_size
+        nb_slot = self._page_len // bs
+        runs: Dict[int, Tuple[Any, List[int], List[int]]] = {}
+        payloads, dst = [], []
+        for j, it in enumerate(items):
+            if isinstance(it, PageRef):
+                run = runs.setdefault(id(it.pool), (it.pool, [], []))
+                run[1].append(it.page)
+                run[2].append(start + j)
+            else:
+                payloads.append(it)
+                dst.append(start + j)
+        for pool, src_pages, dst_pages in runs.values():
+            n = len(src_pages)
+            src_idx = np.zeros(nb_slot, np.int32)
+            dst_idx = np.zeros(nb_slot, np.int32)
+            src_idx[:n], dst_idx[:n] = src_pages, dst_pages
+            pcache = _pool_copy(pcache, pool.cache, src_idx, dst_idx, n,
+                                block_size=bs)
+        if payloads:
+            pcache = KC.insert_paged_state(
+                pcache, row, KC.pages_from_payloads(payloads,
+                                                    len(items) * bs),
+                dst, bs, scatter=_page_scatter)
+        return pcache, len(items) - len(payloads)
 
     def _match_len(self, tokens: np.ndarray, keys: List[bytes]) -> int:
         """Tentative match length for batch planning: no stats, no fetch."""
@@ -395,8 +439,12 @@ class PrefillEngine:
         return max(matched - matched % self.ecfg.block_size, 0)
 
     def _publish(self, tokens: np.ndarray, st: Dict[str, Any],
-                 matched: int, keys: List[bytes]) -> None:
-        """Insert freshly computed full blocks into the global store."""
+                 matched: int, keys: List[bytes],
+                 pages: Optional[Tuple[Dict[str, Any], np.ndarray]] = None
+                 ) -> None:
+        """Insert freshly computed full blocks into the global store.  A
+        paged wave passes ``pages``, its pool and the row's block-table
+        row: the pages ARE the blocks, cut out in one program."""
         bs = self.ecfg.block_size
         if not keys:
             return
@@ -404,9 +452,13 @@ class PrefillEngine:
         self._leading[keys[0]] = max(self._leading.get(keys[0], 0), n_full)
         if self.store is None:
             return
-        if "n_blocks" in st:     # paged wave state: pages ARE the blocks
-            payloads = [KC.paged_state_block(st, j, bs)
-                        for j in range(matched // bs, n_full // bs)]
+        if pages is not None:
+            pool, table_row = pages
+            ids = table_row[matched // bs: n_full // bs]
+            idx = np.zeros(len(table_row), np.int32)
+            idx[:len(ids)] = ids
+            payloads = list(_page_payloads(pool, idx,
+                                           block_size=bs)[:len(ids)])
         else:
             payloads = [KC.slice_prefix_kv(st, i, i + bs)
                         for i in range(matched, n_full, bs)]
@@ -586,38 +638,40 @@ class PrefillEngine:
         use_paged = hit and len(chain) == 1 and self._paged_inc
         bs = self.ecfg.block_size
         matched_of: Dict[int, int] = {}
-        with tracing.span("prefill.stage"):
+        with tracing.span("prefill.stage") as sp:
             if use_paged:
                 nb_slot = self._page_len // bs
                 pcache = T.init_paged_cache(
                     self.scfg, n_rows, self.ecfg.max_len, bs,
                     dtype=self.params["embed"].dtype)
-                # host mirror of the wave's block tables: each row owns a
-                # contiguous run of wave-local pages (prefix pages first,
-                # then fresh pages covering this wave's padded suffix)
+                # host mirror of the wave's block tables and lengths: each
+                # row owns a contiguous run of wave-local pages (prefix
+                # pages first, then fresh pages covering this wave's
+                # padded suffix)
                 tables = np.full((n_rows, nb_slot), -1, np.int32)
+                lengths = np.zeros((n_rows,), np.int32)
+                pages_in = 0
                 for row, i in enumerate(chosen):
-                    part = None
+                    start = 1 + row * nb_slot
                     if i in partials:
                         # resume a chunked row: its parked state is
                         # already in the paged wire format
                         matched_of[i] = progress[i]
                         part = partials.pop(i)
+                        pcache = KC.insert_paged_state(
+                            pcache, row, part,
+                            list(range(start, start + int(part["n_blocks"]))),
+                            bs, scatter=_page_scatter)
                     else:
-                        matched, payloads = self._match(toks[i],
-                                                        keys_of[i])
+                        matched, items = self._match(toks[i], keys_of[i],
+                                                     pages=True)
                         matched_of[i] = store_matched[i] = matched
                         if matched > 0:
                             reqs[i].cached_tokens = matched
-                            part = KC.pages_from_payloads(payloads,
-                                                          matched)
-                    start = 1 + row * nb_slot
-                    if part is not None:
-                        n_have = int(part["n_blocks"])
-                        pcache = KC.insert_paged_state(
-                            pcache, row, part,
-                            list(range(start, start + n_have)), bs,
-                            scatter=_page_scatter)
+                            pcache, n = self._stage_prefix(
+                                pcache, row, items, start)
+                            pages_in += n
+                    lengths[row] = matched_of[i]
                     # fresh pages out to the wave's padded write horizon
                     # (pad junk lands in the row's own junk pages, same
                     # overwrite-before-read contract as the dense path)
@@ -626,7 +680,9 @@ class PrefillEngine:
                     tables[row, :n_need] = np.arange(start,
                                                      start + n_need)
                 pcache["block_tables"] = jnp.asarray(tables)
+                pcache["lengths"] = jnp.asarray(lengths)
                 caches = [pcache]
+                sp.set_metadata(pages_in=pages_in)
             else:
                 caches = [T.init_cache(e.scfg, n_rows, self.ecfg.max_len,
                                        dtype=e.params["embed"].dtype)
@@ -714,11 +770,14 @@ class PrefillEngine:
                 # this prompt is still mid-chunk — same hit pattern as
                 # one-shot prefill
                 pub_from = published.get(i, store_matched.get(i, 0))
-                keys_part = keys_of[i][: new_len // self.ecfg.block_size]
-                if len(keys_part) * self.ecfg.block_size > pub_from:
-                    with tracing.span("prefill.publish"):
-                        self._publish(toks[i], st, pub_from, keys_part)
-                    published[i] = len(keys_part) * self.ecfg.block_size
+                keys_part = keys_of[i][: new_len // bs]
+                if len(keys_part) * bs > pub_from:
+                    with tracing.span("prefill.publish",
+                                      blocks=len(keys_part) - pub_from // bs):
+                        self._publish(toks[i], st, pub_from, keys_part,
+                                      pages=((caches[0], tables[row])
+                                             if use_paged else None))
+                    published[i] = len(keys_part) * bs
                 if new_len < len(toks[i]):
                     # chunk boundary: park the partial state, stay
                     # remaining.  On the paged-wave track partials park in
@@ -831,6 +890,7 @@ class DecodeEngine:
             _span_view(self.cfg, self.params, layer_span)
         self.page_len = _paged_page_len(self.scfg, ecfg)
         self.paged = self.page_len is not None
+        self._page_spec = None        # shapes of one page's payload
         if self.paged:
             self.cache = T.init_paged_cache(self.scfg, ecfg.max_batch,
                                             ecfg.max_len, ecfg.block_size,
@@ -904,6 +964,15 @@ class DecodeEngine:
         """One physical page as a dense per-block store payload (the
         store's demotion/fetch copy-out)."""
         return KC.page_payload(self.cache, int(page), self.ecfg.block_size)
+
+    def page_spec(self) -> Dict[str, Any]:
+        """The shapes of one ``materialize`` payload, with no device work
+        (what the store bills a page it does not copy by)."""
+        if self._page_spec is None:
+            self._page_spec = jax.eval_shape(
+                lambda c: KC.page_payload(c, 0, self.ecfg.block_size),
+                self.cache)
+        return self._page_spec
 
     def slot_pages(self, slot: int) -> List[int]:
         """Physical pages backing ``slot`` in block order (bound+owned)."""

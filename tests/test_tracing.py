@@ -137,6 +137,25 @@ def test_one_prefill_wave_span_per_wave(runs):
                    for e in _spans(events, "prefill.extract"))
 
 
+def test_stage_and_publish_count_the_pages_they_move(runs):
+    """A paged hit wave's stage span counts the store pages it copied in
+    pool to pool (``pages_in``); each publish span counts the blocks it
+    published (``blocks``)."""
+    _, traced, events = runs
+    store = traced["orch"].store
+    waves = sorted(_spans(events, "prefill.wave"), key=lambda e: e[1])
+    pages_in = 0
+    for span, wave in zip(waves, traced["waves"]):
+        stage, = _inside(events, span, "prefill.stage")
+        if wave["hit"]:
+            pages_in += stage[3]["pages_in"]
+        else:
+            assert "pages_in" not in stage[3]
+    assert 0 < pages_in <= store.stats.hit_blocks
+    blocks = [e[3]["blocks"] for e in _spans(events, "prefill.publish")]
+    assert min(blocks) >= 1 and sum(blocks) >= store.stats.inserts > 0
+
+
 def test_one_handoff_span_per_request(runs):
     _, traced, events = runs
     handoffs = _spans(events, "handoff")
