@@ -254,7 +254,7 @@ class Bench:
             req = traffic.make_request(rid, gen.prompt(spec), spec.output)
             return Record(rid, due, segment, req, req.prompt_len)
 
-        state: Dict[str, Any] = {}
+        state: Dict[str, Any] = {"built": len(self.tally.names)}
 
         def on_open():
             if trace:
@@ -262,6 +262,7 @@ class Bench:
                 state["tracer"] = tr.Tracer()
                 state["tracer"].start()
             state["compiles"] = self.tally.programs
+            state["opened"] = len(self.tally.names)
             state["t_open"] = now()
             if trace:
                 state["tracer"].open_window()
@@ -269,6 +270,7 @@ class Bench:
         def on_close():
             state["t_close"] = now()
             state["compiles"] = self.tally.programs - state["compiles"]
+            state["closed"] = len(self.tally.names)
             if trace:
                 state["tracer"].close_window()
 
@@ -284,6 +286,13 @@ class Bench:
         self.log(f"window opened {t_open - t0:.1f}s after the ramp began, "
                  f"{state['t_close'] - t_open:.1f}s long; "
                  f"{state['compiles']} programs compiled inside it")
+        names = self.tally.names
+        for part, lo, hi in (("ramp", "built", "opened"),
+                             ("window", "opened", "closed")):
+            built = [f"{n}{' (cache)' if hit else ''}"
+                     for n, hit in names[state[lo]:state[hi]]]
+            if built:
+                self.log(f"programs built in the {part}: {', '.join(built)}")
         stats = jax.devices()[0].memory_stats() or {}
         run = Run(self.cfg, mix, cellfile, (t_open, state["t_close"]),
                   drv.records, list(spans.log), {},

@@ -1,11 +1,13 @@
 """The one traffic generator: reads a mix's parameters from
 ``traffic/<name>.json`` and makes its requests from the run's seed.
 
-Sizes and gaps come from the mix's own ``generator_seed``, so every run
-seed serves the same set of prompt and output lengths, prefix groups and
-inter-arrival gaps; the run's seed only orders them and draws the token
-ids (and, elsewhere, the weights).  Runs with different seeds then do the
-same work in another order.
+Sizes, gaps and their order come from the mix's own ``generator_seed``,
+so every run seed serves the same requests at the same offsets: the same
+prompt and output lengths, prefix groups and arrivals.  The run's seed
+draws only the token ids (and, elsewhere, the weights).  Runs with
+different seeds then do the same work; a seed that also ordered the
+requests moved the cell's TTFT tail by more than its bound, because with
+some fifty requests in a window the tail is the few that meet a burst.
 
 Lengths are log-normal (median, sigma) clipped to [min, max]; prompt plus
 output is clipped to ``max_total``.  Shared prefixes: ``prefix_groups``
@@ -15,10 +17,10 @@ request's own suffix.  The Poisson and Zipf arithmetic is that of the
 program's ``serving/workload.py``, copied so that the yardstick stays
 fixed.
 
-Arrivals are Poisson at the cell's rate, open loop.  The schedule has three segments, ramp (before the window), window and tail;
-each holds a fixed number of requests, rate x length, whose exponential
-gaps are scaled to the segment's length exactly, so every seed puts the
-same number of requests in the window.
+Arrivals are Poisson at the cell's rate, open loop.  The schedule has
+three segments, ramp (before the window), window and tail; each holds a
+fixed number of requests, rate x length, whose exponential gaps are
+scaled to the segment's length exactly.
 """
 from __future__ import annotations
 
@@ -118,9 +120,6 @@ class Traffic:
                 continue
             specs = self.specs(n)
             gaps = self._fixed.exponential(1.0, n)
-            # the run's seed orders the segment's shapes and gaps
-            specs = [specs[i] for i in self.rng.permutation(n)]
-            gaps = gaps[self.rng.permutation(n)]
             # scale so the n arrivals fill [t0, t0 + length) exactly: the
             # first comes a fraction of a gap in, the last before the end
             cum = np.cumsum(gaps) / (gaps.sum() + self._fixed.exponential())

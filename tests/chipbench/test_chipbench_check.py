@@ -8,8 +8,8 @@ Runs drive the whole harness (set-up, warm-up, a measured window through
 ``Server``, the check and the result line) on the CPU at a tiny size;
 only the look for a chip is skipped.  The tiny model's limit, 0.012, sits
 between the readings of the seed the tests run: there the program's
-widest gap reads 0 and the control's 0.045.  Over seeds 1-12 the program
-reads 0-0.0070 and the control 0-0.31: at this size float8 does not
+widest gap reads 0 and the control's 0.078.  Over seeds 1-12 the program
+reads 0-0.0072 and the control 0-0.078: at this size float8 does not
 always change a token, so the tests keep to one seed.
 """
 import pathlib
@@ -24,9 +24,10 @@ sys.path.insert(0, str(ROOT))
 
 from benchmarks.chip import correctness, harness, metrics  # noqa: E402
 
-SEED = 1
+SEED = 2
 LIMIT = 0.012
-CFG = dict(name="tiny", source="test", hidden_size=64, num_hidden_layers=2,
+CFG = dict(name="tiny", source="test", model_type="granitemoe",
+           hidden_size=64, num_hidden_layers=2,
            num_attention_heads=4, num_key_value_heads=2, head_dim=16,
            intermediate_size=32, num_local_experts=4, num_experts_per_tok=2,
            vocab_size=8192, hidden_act="silu", rms_norm_eps=1e-6,

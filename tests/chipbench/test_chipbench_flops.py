@@ -12,9 +12,9 @@ sys.path.insert(0, str(ROOT))
 from benchmarks.chip import flops, model  # noqa: E402
 
 MOE = model.load("granite-moe-3b-a800m")
-DENSE = {"num_hidden_layers": 12, "hidden_size": 4096,
-         "num_attention_heads": 32, "num_key_value_heads": 8,
-         "head_dim": 128, "intermediate_size": 14336,
+DENSE = {"model_type": "granite", "num_hidden_layers": 12,
+         "hidden_size": 4096, "num_attention_heads": 32,
+         "num_key_value_heads": 8, "head_dim": 128, "intermediate_size": 14336,
          "num_local_experts": 0, "num_experts_per_tok": 0,
          "vocab_size": 49152, "tie_word_embeddings": False,
          "dtype": "bfloat16"}

@@ -15,7 +15,8 @@ from benchmarks.chip import model, reference  # noqa: E402
 
 
 def tiny(moe: bool, dtype: str = "float32") -> dict:
-    return dict(name="tiny", source="test", hidden_size=64,
+    return dict(name="tiny", source="test",
+                model_type="granitemoe" if moe else "granite", hidden_size=64,
                 num_hidden_layers=3, num_attention_heads=4,
                 num_key_value_heads=2, head_dim=16, intermediate_size=32,
                 num_local_experts=4 if moe else 0,

@@ -1,5 +1,6 @@
 """The traffic generator: the same seed gives the same requests, every
-seed the same sizes in another order, and lengths stay in their clips."""
+seed the same sizes at the same offsets with its own token ids, and
+lengths stay in their clips."""
 import collections
 import pathlib
 import sys
@@ -31,13 +32,18 @@ def test_same_seed_same_requests():
 
 
 def test_other_seed_same_sizes_other_order():
-    _, s1 = _schedule(1)
-    _, s2 = _schedule(2)
+    """Another seed serves the same sizes, in the same order and at the
+    same offsets (so it does the same work); only the token ids differ."""
+    g1, s1 = _schedule(1)
+    g2, s2 = _schedule(2)
     for seg in ("ramp", "window", "tail"):
-        a = [(s.group, s.suffix, s.output) for _, s, g in s1 if g == seg]
-        b = [(s.group, s.suffix, s.output) for _, s, g in s2 if g == seg]
-        assert collections.Counter(a) == collections.Counter(b)
-    assert [s for _, s, _ in s1] != [s for _, s, _ in s2]
+        a = [(t, s.group, s.suffix, s.output) for t, s, g in s1 if g == seg]
+        b = [(t, s.group, s.suffix, s.output) for t, s, g in s2 if g == seg]
+        assert a and a == b
+    shared = next(s for _, s, _ in s1 if s.group >= 0)
+    assert not np.array_equal(g1.prompt(shared), g2.prompt(shared))
+    assert not np.array_equal(g1.groups[shared.group],
+                              g2.groups[shared.group])
 
 
 def test_window_holds_rate_times_length_requests():
